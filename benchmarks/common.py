@@ -3,7 +3,9 @@
 from __future__ import annotations
 
 import dataclasses
+import os
 import time
+from pathlib import Path
 from typing import Dict, List, Tuple
 
 import numpy as np
@@ -11,6 +13,26 @@ import numpy as np
 from repro.core.lexicon import Lexicon, make_lexicon
 from repro.core.strategies import StrategyConfig
 from repro.core.text_index import IndexSetConfig, TextIndexSet
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    nothing is set here.  Otherwise the cache goes to ``<repo>/.jax_cache``
+    — a fixed path, because the path is part of the cache key, so a
+    directory that moves between runs never hits.  Call it from a
+    program's ``main`` before the first compile; never at import and
+    never in tests.  Returns the directory in use.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    path = str(Path(__file__).resolve().parents[1] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 @dataclasses.dataclass

@@ -316,6 +316,9 @@ def main() -> int:
                          "('' disables)")
     args = ap.parse_args()
     names = args.only.split(",") if args.only else list(BENCHES)
+    from benchmarks.common import enable_compile_cache
+
+    print(f"compile cache: {enable_compile_cache()}")
 
     all_rows = []
     verdicts = []

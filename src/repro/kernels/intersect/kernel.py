@@ -49,7 +49,7 @@ def intersect_kernel(
     *,
     bn: int = 1024,
     bm: int = 1024,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     N, M = a.shape[0], b.shape[0]
     assert N % bn == 0 and M % bm == 0, (N, M, bn, bm)

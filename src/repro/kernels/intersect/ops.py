@@ -4,20 +4,18 @@ from __future__ import annotations
 
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import DeviceCounts, interpret_mode
 from repro.kernels.intersect.kernel import intersect_kernel
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def intersect_sorted(a, b, bn: int = 1024, bm: int = 1024):
+def intersect_sorted(a, b, bn: int = 1024, bm: int = 1024,
+                     counts: Optional[DeviceCounts] = None):
     """mask[i] = a[i] in b for sorted int32 arrays (host-callable; pads to
-    block multiples with sentinels that can never match)."""
+    block multiples with sentinels that can never match).  ``counts``
+    records the launch."""
     a = jnp.asarray(a, jnp.int32)
     b = jnp.asarray(b, jnp.int32)
     N, M = a.shape[0], b.shape[0]
@@ -28,19 +26,24 @@ def intersect_sorted(a, b, bn: int = 1024, bm: int = 1024):
     big = jnp.iinfo(jnp.int32).max
     ap = jnp.concatenate([a, jnp.full((pn,), big - 1, a.dtype)])
     bp = jnp.concatenate([b, jnp.full((pm,), big, b.dtype)])
-    mask = intersect_kernel(
-        ap, bp, bn=bn, bm=bm, interpret=not _on_tpu()
-    )
+    interpret = interpret_mode()
+    mask = intersect_kernel(ap, bp, bn=bn, bm=bm, interpret=interpret)
+    if counts is not None:
+        counts.launch("intersect", interpret)
     return mask[:N]
 
 
-def doc_member_mask(a_docs: np.ndarray, b_docs: np.ndarray) -> Optional[np.ndarray]:
+def doc_member_mask(
+    a_docs: np.ndarray, b_docs: np.ndarray,
+    counts: Optional[DeviceCounts] = None,
+) -> Optional[np.ndarray]:
     """Host mask[i] = a_docs[i] occurs in b_docs, via the Pallas kernel.
 
     The doc-level prefilter of the proximity search pallas backend
     (``repro.search.join.pallas_window_join``).  ``a_docs`` must be sorted;
     ``b_docs`` is deduplicated here.  Returns None when the doc ids do not
-    fit the kernel's int32 key width — callers fall back to a host join.
+    fit the kernel's int32 key width — callers fall back to a host join
+    (and record it as the ``intersect_docs`` fallback).
     """
     if a_docs.size == 0 or b_docs.size == 0:
         return np.zeros(a_docs.shape, dtype=bool)
@@ -49,5 +52,6 @@ def doc_member_mask(a_docs: np.ndarray, b_docs: np.ndarray) -> Optional[np.ndarr
         int(b_docs[-1]) >= np.iinfo(np.int32).max
     ):
         return None
-    mask = intersect_sorted(a_docs.astype(np.int32), b_docs.astype(np.int32))
+    mask = intersect_sorted(a_docs.astype(np.int32), b_docs.astype(np.int32),
+                            counts=counts)
     return np.asarray(mask).astype(bool)

@@ -54,9 +54,9 @@ def varint_unpack_kernel(
     contrib: jnp.ndarray,  # (M,) int32 shifted payloads
     n_values: int,         # N, a multiple of bn
     *,
-    bn: int = 256,
+    bn: int = 1024,
     bm: int = 1024,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     M = vid.shape[0]
     assert n_values % bn == 0 and M % bm == 0, (n_values, M, bn, bm)

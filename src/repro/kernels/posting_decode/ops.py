@@ -12,7 +12,8 @@ Device-width gate: jax runs with 64-bit disabled, so the jax/pallas
 paths are taken only when every varint in the block fits 4 bytes (28
 payload bits < int32).  Wider varints fall back to the exact int64 host
 path — callers never see a difference (the parity suite in
-``tests/test_kernels.py`` pins this bit-for-bit).
+``tests/test_kernels.py`` pins this bit-for-bit), and a
+:class:`~repro.kernels.DeviceCounts` passed in records the fallback.
 """
 
 from __future__ import annotations
@@ -24,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import DeviceCounts, interpret_mode
 from repro.kernels.intersect.ops import doc_member_mask
 from repro.kernels.posting_decode.kernel import varint_unpack_kernel
 from repro.kernels.posting_decode.ref import (
@@ -45,10 +47,6 @@ _MAX_DEVICE_VARINT_BYTES = 4
 _PALLAS_MIN_BYTES = 1 << 14
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def _pow2(n: int, floor: int = 8) -> int:
     return max(floor, 1 << int(np.ceil(np.log2(max(n, 1)))))
 
@@ -58,13 +56,15 @@ def _segment_sum_jit(contrib, vid, num_segments: int):
     return jax.ops.segment_sum(contrib, vid, num_segments=num_segments)
 
 
-def unpack_varints(buf, backend: str = "numpy") -> np.ndarray:
+def unpack_varints(buf, backend: str = "numpy",
+                   counts: Optional[DeviceCounts] = None) -> np.ndarray:
     """Decode a terminator-aligned byte buffer's varints as (N,) int64.
 
     ``backend`` picks where the segmented sum runs; the byte prep (flag
     scan, ranks, shifts) is host work either way.  Blocks containing a
     varint wider than the int32 gate run the host path regardless — the
-    result is always exact int64.
+    result is always exact int64.  ``counts`` records that fallback and
+    every kernel launch.
     """
     if backend not in DECODE_BACKENDS:
         raise ValueError(
@@ -77,6 +77,8 @@ def unpack_varints(buf, backend: str = "numpy") -> np.ndarray:
     contrib, vid, n_vals = byte_prep(buf)
     widths = np.bincount(vid, minlength=n_vals)
     if widths.max(initial=0) > _MAX_DEVICE_VARINT_BYTES:
+        if counts is not None:
+            counts.fallback("varint_width")
         return unpack_varints_np(buf)
     if backend == "jax":
         # pad bytes AND segments to power-of-two buckets: chunk payloads
@@ -98,9 +100,12 @@ def unpack_varints(buf, backend: str = "numpy") -> np.ndarray:
         )
         return np.asarray(values[:n_vals]).astype(np.int64)
     # pallas: pad bytes with a sentinel id beyond every output slot and
-    # values to the block grid; sentinel bytes can never hit a slot
+    # values to the block grid; sentinel bytes can never hit a slot.
+    # Each 1-D block is the whole padded array or a multiple of 1024:
+    # XLA tiles longer int32 vectors T(1024), and Mosaic refuses a block
+    # that does not match that tiling
     M = int(contrib.size)
-    bn = min(256, _pow2(n_vals))
+    bn = min(1024, _pow2(n_vals))
     bm = min(1024, _pow2(M))
     n_pad = (-n_vals) % bn
     m_pad = (-M) % bm
@@ -108,14 +113,17 @@ def unpack_varints(buf, backend: str = "numpy") -> np.ndarray:
         [vid, np.full(m_pad, n_vals + n_pad, dtype=np.int64)]
     )
     contrib_p = np.concatenate([contrib, np.zeros(m_pad, dtype=np.int64)])
+    interpret = interpret_mode()
     values = varint_unpack_kernel(
         jnp.asarray(vid_p, jnp.int32),
         jnp.asarray(contrib_p, jnp.int32),
         n_vals + n_pad,
         bn=bn,
         bm=bm,
-        interpret=not _on_tpu(),
+        interpret=interpret,
     )
+    if counts is not None:
+        counts.launch("varint_unpack", interpret)
     return np.asarray(values[:n_vals]).astype(np.int64)
 
 
@@ -128,16 +136,19 @@ class DeviceDecoder:
     tail), same ``state()``/``set_state()`` carry tuple — a stream may
     be suspended under one decoder and resumed under the other.  The
     delta expansion stays exact host int64; only the byte-crunching
-    varint unpack is dispatched to the device.
+    varint unpack is dispatched to the device; ``counts`` records its
+    launches and host fallbacks.
     """
 
-    def __init__(self, backend: str = "jax"):
+    def __init__(self, backend: str = "jax",
+                 counts: Optional[DeviceCounts] = None):
         if backend not in DECODE_BACKENDS:
             raise ValueError(
                 f"unknown decode backend {backend!r}; expected one of "
                 f"{DECODE_BACKENDS}"
             )
         self.backend = backend
+        self.counts = counts
         self._rem = b""
         self._prev_doc = 0
         self._prev_pos = 0
@@ -153,7 +164,8 @@ class DeviceDecoder:
         backend = self.backend
         if backend == "pallas" and cut < _PALLAS_MIN_BYTES:
             backend = "jax"
-        values = unpack_varints(buf[:cut], backend=backend)
+        values = unpack_varints(buf[:cut], backend=backend,
+                                counts=self.counts)
         posts, (pd, pp, st) = expand_deltas(
             values, self._prev_doc, self._prev_pos, self._any
         )

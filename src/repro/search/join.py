@@ -160,20 +160,25 @@ def jax_window_join(a: np.ndarray, b: np.ndarray, window: int) -> np.ndarray:
 
 
 # --------------------------------------------------------- pallas backend --
-def pallas_window_join(a: np.ndarray, b: np.ndarray, window: int) -> np.ndarray:
+def pallas_window_join(a: np.ndarray, b: np.ndarray, window: int,
+                       counts=None) -> np.ndarray:
     """Doc-level prefilter with the Pallas intersect kernel, exact finish.
 
     The kernel computes membership of ``a``'s doc ids in ``b``'s doc ids
     (dense tile compare — the TPU-native formulation); only rows in common
     docs reach the exact host window join, which on real queries is a tiny
-    fraction of the input.
+    fraction of the input.  ``counts`` (a
+    :class:`~repro.kernels.DeviceCounts`) records the kernel launch or the
+    host fallback.
     """
     if a.size == 0 or b.size == 0:
         return np.zeros((0, 2), dtype=np.int64)
     from repro.kernels.intersect.ops import doc_member_mask
 
-    mask = doc_member_mask(a[:, 0], b[:, 0])
+    mask = doc_member_mask(a[:, 0], b[:, 0], counts=counts)
     if mask is None:  # doc ids beyond the kernel's int32 keys
+        if counts is not None:
+            counts.fallback("intersect_docs")
         return numpy_window_join(a, b, window)
     a_hit = a[mask]
     if a_hit.size == 0:

@@ -66,6 +66,7 @@ class CacheStats:
     pool_hits: int = 0       # chunk replays served by a batch ChunkPool
     device_hits: int = 0     # cursors served from the device-buffer tier
     partial_admits: int = 0  # settled prefixes admitted by early stops
+    device_rejects: int = 0  # drained lists too wide for the int32 device tier
 
     @property
     def hit_rate(self) -> float:
@@ -214,8 +215,10 @@ class PostingCache:
 
     def put_device(self, index_name: str, key: Hashable, buf) -> None:
         """Pin a decoded list as a device buffer beside the host entry.
-        The buffer shares the byte budget (charged at its nbytes)."""
+        The buffer shares the byte budget (charged at its nbytes).  None
+        is a list the device integer cannot hold: counted, not pinned."""
         if buf is None:
+            self.stats.device_rejects += 1
             return
         if self._charge(buf) > self.budget:
             return

@@ -240,6 +240,10 @@ class _FabricCacheStats:
         return self._sum("partial_admits")
 
     @property
+    def device_rejects(self) -> int:
+        return self._sum("device_rejects")
+
+    @property
     def pool_hits(self) -> int:
         return self._sum("pool_hits") + self.pool_hits_extra
 

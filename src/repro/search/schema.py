@@ -27,7 +27,7 @@ from typing import Dict, FrozenSet
 
 # Block name -> allowed keys.  "" is the top level of ``last_trace``;
 # the other blocks are the nested dicts stored under the same-named
-# top-level key ("topk", "cache", "replicas", "compactions").
+# top-level key ("topk", "cache", "replicas", "compactions", "device").
 TRACE_SCHEMA: Dict[str, FrozenSet[str]] = {
     "": frozenset({
         # scatter-fetch wave accounting (stage 2)
@@ -35,7 +35,7 @@ TRACE_SCHEMA: Dict[str, FrozenSet[str]] = {
         "lookups_planned", "lookups_fetched", "lookups_deferred",
         "prefetched_waves", "overlapped_finalizes", "shard_fetch_s",
         # batch-level pins and nested blocks
-        "snapshot", "topk", "cache", "compactions", "replicas",
+        "snapshot", "topk", "cache", "compactions", "replicas", "device",
     }),
     "topk": frozenset({
         "queries", "ranked_queries",
@@ -56,6 +56,11 @@ TRACE_SCHEMA: Dict[str, FrozenSet[str]] = {
     }),
     "compactions": frozenset({
         "compactions", "compacted_streams",
+    }),
+    # per-batch device-path tally (repro.kernels.DeviceCounts): each value
+    # maps a kernel in SEARCH_KERNELS / a site in FALLBACK_SITES to a count
+    "device": frozenset({
+        "compiled_launches", "interpreted_launches", "host_fallbacks",
     }),
 }
 

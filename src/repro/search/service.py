@@ -52,6 +52,7 @@ from repro.search.join import (
     numpy_phrase_join,
     numpy_window_join,
     pack_keys,
+    pallas_window_join,
 )
 from repro.search.plan import (
     ROUTE_MULTI,
@@ -66,6 +67,7 @@ from repro.search.plan import (
     plan_batch,
 )
 from repro.core.inverted_index import PostingCursor
+from repro.kernels import DeviceCounts
 from repro.kernels.posting_decode.ops import DeviceDecoder
 from repro.search.pool import ChunkPool
 from repro.search.reader import IndexSetReader, ShardedIndexSetReader
@@ -133,6 +135,11 @@ class SearchService:
     buffers in the posting cache; defaults to on for the jax/pallas
     backends, off for numpy/callable.  Both knobs change I/O and
     residency only — results stay element-wise identical.
+
+    ``last_trace['device']`` reports, per batch, the Pallas launches
+    (compiled or interpreted) and every exact host fallback taken where a
+    value did not fit the device's int32 integers
+    (:class:`~repro.kernels.DeviceCounts`).
     """
 
     def __init__(
@@ -180,6 +187,8 @@ class SearchService:
                 f"{sorted(JOIN_BACKENDS)} or a callable"
             )
         self.share_chunks = bool(share_chunks)
+        # the current batch's device-path tally (fresh per search_batch)
+        self._device = DeviceCounts()
         if device_decode is None:
             device_decode = self.backend in ("jax", "pallas")
         self.device_decode = bool(device_decode)
@@ -188,7 +197,8 @@ class SearchService:
                 self.backend if self.backend in ("jax", "pallas") else "jax"
             )
             self._make_decoder: Optional[Callable[[], DeviceDecoder]] = (
-                lambda: DeviceDecoder(backend=dec_backend)
+                lambda: DeviceDecoder(backend=dec_backend,
+                                      counts=self._device)
             )
         else:
             self._make_decoder = None
@@ -231,6 +241,9 @@ class SearchService:
         # observe a different collection state than the plan did
         self.reader.refresh()
         snapshot = list(self.reader.generation_vector())
+        self._device = DeviceCounts()
+        cs = self.reader.cache_stats
+        rejects0 = cs.device_rejects if cs is not None else 0
         plan = self.plan(queries)                               # stage 1
         results: List[Optional[QueryResult]] = [None] * len(plan.queries)
         ordinary: List[Tuple[int, List[ShardPosts]]] = []
@@ -289,13 +302,16 @@ class SearchService:
             rt["failovers_batch"] = rt["failovers"] - self._failovers_seen
             self._failovers_seen = rt["failovers"]
             self.last_trace["replicas"] = rt
+        if cs is not None:
+            # the device tier refuses inside the reader's cache admission
+            self._device.fallback("device_rows", cs.device_rejects - rejects0)
+        self.last_trace["device"] = self._device.as_trace()
         self.check_trace_complete(plan)
         # serving-health counters: cumulative posting-cache stats (the
         # full_drops count is THE regression signal for targeted
         # invalidation — it moves only when a reader fell back to a
         # whole-namespace sweep) and the substrate's background-compaction
         # totals, so traces tie a batch to the maintenance that preceded it
-        cs = self.reader.cache_stats
         if cs is not None:
             self.last_trace["cache"] = {
                 "hits": cs.hits,
@@ -489,6 +505,9 @@ class SearchService:
     ) -> List[np.ndarray]:
         if self.backend == "jax":
             return self._join_many_jax(pairs)
+        if self.backend == "pallas":
+            return [pallas_window_join(a, b, w, counts=self._device)
+                    for a, b, w in pairs]
         join = self.backend if callable(self.backend) else JOIN_BACKENDS[self.backend]
         return [join(a, b, w) for a, b, w in pairs]
 
@@ -507,6 +526,7 @@ class SearchService:
             dtype = _jax_dtype_for(int(max(akey[-1], bkey[-1])), w)
             if dtype is None:
                 # packed keys exceed the device integer width: exact host join
+                self._device.fallback("join_keys")
                 out[idx] = numpy_window_join(a, b, w)
                 continue
             shape = (_pow2(akey.shape[0]), _pow2(bkey.shape[0]),

@@ -11,6 +11,7 @@ from repro.kernels.embedding_bag.ops import embedding_bag_fixed
 from repro.kernels.embedding_bag.ref import embedding_bag_fixed_ref
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import flash_attention_ref
+from repro.kernels import DeviceCounts, interpret_mode
 from repro.kernels.intersect.ops import intersect_sorted
 from repro.kernels.intersect.ref import intersect_sorted_ref
 from repro.kernels.paged_attention.ops import paged_attention
@@ -135,6 +136,20 @@ def test_intersect_disjoint_and_identical():
     assert np.asarray(intersect_sorted(a, a)).all()
 
 
+@pytest.mark.parametrize("platform,interpret", [
+    ("cpu", True), ("tpu", False), ("gpu", None),
+])
+def test_interpret_mode_only_on_cpu(monkeypatch, platform, interpret):
+    """The one interpret decision: interpreted on cpu, compiled on tpu,
+    and no quiet interpretation anywhere else."""
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    if interpret is None:
+        with pytest.raises(RuntimeError):
+            interpret_mode()
+    else:
+        assert interpret_mode() is interpret
+
+
 # ----------------------------------------------- posting decode parity --
 def _posting_stream(n, seed, max_doc=50, max_pos=200_000):
     rng = np.random.RandomState(seed)
@@ -174,8 +189,12 @@ def test_unpack_varints_wide_values_exact(backend):
     """Values past 28 payload bits (up to near 2^63) stay exact — the
     device paths detect the wide varint and defer to host int64."""
     wide = [3, 1 << 40, 127, (1 << 62) - 5, 0, 1 << 28]
-    got = unpack_varints(_varint_buf(wide), backend=backend)
+    counts = DeviceCounts()
+    got = unpack_varints(_varint_buf(wide), backend=backend, counts=counts)
     assert got.tolist() == wide
+    # the device paths record the host fallback; numpy never leaves it
+    want = {} if backend == "numpy" else {"varint_width": 1}
+    assert dict(counts.fallbacks) == want
 
 
 def test_unpack_varints_unknown_backend_rejected():

@@ -441,3 +441,48 @@ def test_index_reader_own_device(small_world):
     assert posts.shape[0] > 0
     assert idx.mgr.device.stats.total_ops == build_before
     assert reader.io_stats().total_ops > 0
+
+
+@functools.lru_cache(maxsize=None)
+def _far_world(doc0: int):
+    """A tiny set whose doc ids start at ``doc0``, with an ordinary-route
+    pair and a single-lookup word pair lifted from adjacent tokens."""
+    lex = make_lexicon(n_words=2000, n_lemmas=900, n_stop=20,
+                       n_frequent=80, seed=5)
+    toks, offs = generate_part(lex, n_docs=40, avg_doc_len=200, doc0=doc0,
+                               seed=3)
+    cfg = IndexSetConfig(strategy=StrategyConfig.set2(cluster_size=256),
+                         fl_area_clusters=64)
+    ts = TextIndexSet(cfg, lex, seed=0)
+    ts.add_documents(toks, offs, doc0)
+    _, cls = lex.classify_words(toks)
+
+    def pair(c):
+        s = int(np.flatnonzero((cls[:-1] == c) & (cls[1:] == c))[0])
+        return (int(toks[s]), int(toks[s + 1]))
+
+    return ts, {"ordinary": Query(pair(OTHER)),
+                "topk": Query(pair(FREQUENT), top_k=5)}
+
+
+@pytest.mark.parametrize("backend,doc0,kind,site,per_batch", [
+    # doc ids fit int32, packed (doc, pos) join keys do not
+    ("jax", 20_000_000, "ordinary", "join_keys", (1, 1)),
+    # doc ids beyond the intersect kernel's int32 keys
+    ("pallas", 2 ** 31, "ordinary", "intersect_docs", (1, 1)),
+    # the drained list is too wide for the device tier; the second batch
+    # is a host-tier hit and drains nothing
+    ("jax", 2 ** 31, "topk", "device_rows", (1, 0)),
+])
+def test_host_fallbacks_exact_and_counted_per_batch(backend, doc0, kind,
+                                                    site, per_batch):
+    ts, queries = _far_world(doc0)
+    q = queries[kind]
+    ref = SearchService(ts, backend="numpy").search_batch([q])[0]
+    assert ref.docs.size and ref.route == (
+        ROUTE_ORDINARY if kind == "ordinary" else ROUTE_WV)
+    svc = SearchService(ts, backend=backend)
+    for want in per_batch:
+        assert svc.search_batch([q])[0] == ref
+        fb = svc.last_trace["device"]["host_fallbacks"]
+        assert fb == {s: (want if s == site else 0) for s in fb}, fb
