@@ -14,7 +14,7 @@ Benches:
     search_ranked — score-ordered (WAND) top-k vs exhaustive ranked scan
     search_hot_traffic — concurrent hot-vocabulary queries through the
                     cross-query chunk pool vs per-query cursors
-    search_replicas — replica read tier: capacity vs replica count,
+    search_replicas — replica read tier: read-bytes balance over replicas,
                     failover sweep across backends × shard counts
     update_speed  — live per-shard update streams: targeted invalidation
                     vs whole-namespace drops under interleaved updates
@@ -177,13 +177,14 @@ def _bench_search_replicas(scale):
         summary["identical"]
         and all(r["identical"] for r in sweep)
         and all(r["failovers"] >= 1 for r in sweep)
-        and summary["capacity_ratio"] >= 1.5
+        and summary["bytes_balance"] <= search_speed.BALANCE_GATE
     )
     return rows + sweep, [
         f"{'PASS' if ok else 'FAIL'}  3-replica fabric identical to the "
         f"single-reader path across backends x shard counts "
         f"(incl. {sum(r['failovers'] for r in sweep)} injected failovers) "
-        f"at {summary['capacity_ratio']:.2f}x single-replica capacity, "
+        f"with the busiest replica at {summary['bytes_balance']:.2f}x the "
+        f"mean read bytes, "
         f"p99 {summary['p99_ms']:.2f} ms"
     ]
 

@@ -44,7 +44,8 @@ PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}" python -m benchmarks.update_speed \
 # must serve results element-wise identical to the single-reader path
 # (across backends and shard counts, including one replica killed
 # mid-batch by an injected fault, which must force a real failover)
-# with balanced routing lifting serving capacity >= 1.2x
+# with routing that keeps the busiest replica's read bytes within 1.25x
+# of the mean
 PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}" python -m benchmarks.search_speed \
   --replicas 2 --scale 0.05 --queries 12 --backend numpy
 # tiny-corpus smoke of the durable on-disk backend: the WAL-fed store

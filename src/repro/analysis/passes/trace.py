@@ -4,7 +4,10 @@
 runtime checker and the code writing the trace agree on the key set, so
 every key written into ``SearchService.last_trace`` (directly, through a
 local later stored into it, or through a dict parameter named ``trace``)
-must appear in ``repro.search.schema.TRACE_SCHEMA``.  Counters that are
+must appear in ``repro.search.schema.TRACE_SCHEMA``.  So must every span
+name: the first argument of a ``<recorder>.span(...)`` call is a string
+literal declared in ``TRACE_SCHEMA["spans"]``, the block
+``last_trace["spans"]`` reports them in.  Counters that are
 members of a completeness partition must additionally be written with
 integer expressions — PR 7 accumulated ``any(...)`` bools into
 ``early_terminated``, which saturated the count at 1 per batch while
@@ -21,6 +24,7 @@ from repro.analysis.schema import Finding
 from repro.search.schema import TRACE_COUNTERS, TRACE_SCHEMA
 
 ALL_TRACE_KEYS = frozenset().union(*TRACE_SCHEMA.values())
+SPAN_NAMES = TRACE_SCHEMA["spans"]
 
 _BOOLISH_CALLS = {"any", "all", "bool"}
 
@@ -93,6 +97,30 @@ class TraceSchemaPass(LintPass):
         out: List[Finding] = []
         for scope in _scopes(tree):
             out.extend(self._check_scope(scope, path))
+        out.extend(self._check_spans(tree, path))
+        return out
+
+    def _check_spans(self, tree: ast.AST, path: str) -> List[Finding]:
+        out: List[Finding] = []
+        for node in ast.walk(tree):
+            if not (isinstance(node, ast.Call)
+                    and isinstance(node.func, ast.Attribute)
+                    and node.func.attr == "span" and node.args):
+                continue
+            arg = node.args[0]
+            if not (isinstance(arg, ast.Constant)
+                    and isinstance(arg.value, str)):
+                out.append(self.finding(
+                    path, node,
+                    "span name is not a string literal; the trace schema "
+                    "can only check a literal",
+                ))
+            elif arg.value not in SPAN_NAMES:
+                out.append(self.finding(
+                    path, node,
+                    f"span {arg.value!r} is not declared in "
+                    f"repro.search.schema.TRACE_SCHEMA['spans']",
+                ))
         return out
 
     # -------------------------------------------------------------------

@@ -43,6 +43,7 @@ from repro.core.postings import (
     encode_postings,
     max_doc_run,
 )
+from repro.core.spans import decode_span
 from repro.core.strategies import StrategyConfig
 from repro.core.stream import DigestLog, StreamManager
 
@@ -583,7 +584,10 @@ class InvertedIndex:
 
         I/O is charged to ``device`` when given (how readers separate
         search accounting from the build device — see
-        ``repro.search.reader``); otherwise to the build device."""
+        ``repro.search.reader``); otherwise to the build device.  Inside
+        a search batch each decode of posting bytes, here and in
+        :meth:`open_cursor`, is an ``index.decode`` span with its byte
+        count (:mod:`repro.core.spans`)."""
         e = self.dict.get(key)
         dev = device if device is not None else self.mgr.device
         if e is None:
@@ -591,15 +595,18 @@ class InvertedIndex:
             return _EMPTY
         dev.read_small(ENTRY_FIXED_BYTES + len(key_bytes(key)) + len(e.data))
         if e.kind == K_EM:
-            posts, _ = decode_postings(bytes(e.data))
+            with decode_span(len(e.data)):
+                posts, _ = decode_postings(bytes(e.data))
             return posts
         data = self.mgr.read_stream(e.sid, device=dev)
         if e.kind == K_TAG:
-            posts, tags = decode_postings(data, tagged=True, zigzag=True)
+            with decode_span(len(data)):
+                posts, tags = decode_postings(data, tagged=True, zigzag=True)
             mine = posts[tags == e.tag]
             order = np.lexsort((mine[:, 1], mine[:, 0]))
             return mine[order]
-        posts, _ = decode_postings(data)
+        with decode_span(len(data)):
+            posts, _ = decode_postings(data)
         return posts
 
     def open_cursor(
@@ -637,7 +644,8 @@ class InvertedIndex:
             return PostingCursor.from_array(_EMPTY)
         dev.read_small(ENTRY_FIXED_BYTES + len(key_bytes(key)) + len(e.data))
         if e.kind == K_EM:
-            posts, _ = decode_postings(bytes(e.data))
+            with decode_span(len(e.data)):
+                posts, _ = decode_postings(bytes(e.data))
             return PostingCursor.from_array(posts)
         if e.kind == K_TAG:
             # one deferred chunk: charged only if the cursor is consumed.
@@ -654,7 +662,9 @@ class InvertedIndex:
             def read_tagged(snap=snap, tag=e.tag, charges=charges):
                 for charge in charges:
                     charge(dev)
-                posts, tags = decode_postings(snap, tagged=True, zigzag=True)
+                with decode_span(len(snap)):
+                    posts, tags = decode_postings(snap, tagged=True,
+                                                  zigzag=True)
                 mine = posts[tags == tag]
                 order = np.lexsort((mine[:, 1], mine[:, 0]))
                 return mine[order]
@@ -699,7 +709,8 @@ class InvertedIndex:
 
             def fetch(lo=lo, hi=hi, charge=charge):
                 charge(dev)
-                posts, _ = decoder.feed(bytes(st.data[lo:hi]))
+                with decode_span(hi - lo):
+                    posts, _ = decoder.feed(bytes(st.data[lo:hi]))
                 return posts
 
             thunks.append((charge_nb, fetch))

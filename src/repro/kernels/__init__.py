@@ -22,7 +22,8 @@ Kernels:
 The search path's kernels (intersect, posting_decode) report what they
 did into a :class:`DeviceCounts` the caller passes: every launch, and
 every exact host fallback taken because a value did not fit the device's
-int32 integers.
+int32 integers.  The jax window join (``repro.search.join``) reports its
+launches there too.
 """
 
 from __future__ import annotations
@@ -32,9 +33,14 @@ from typing import Dict
 
 import jax
 
-# the search path's Pallas kernels, and the places where it may answer on
-# the host instead of the device (each exact; see DeviceCounts)
-SEARCH_KERNELS = ("intersect", "varint_unpack")
+# the search path's device programs: its Pallas kernels, which run
+# compiled or interpreted, and the jax backend's window join
+# (``batched_window_mask``, one launch per bucket: three host-to-device
+# transfers and one back), which XLA always compiles; and the places
+# where it may answer on the host instead of the device (each exact;
+# see DeviceCounts)
+PALLAS_KERNELS = ("intersect", "varint_unpack")
+SEARCH_KERNELS = PALLAS_KERNELS + ("window_mask",)
 FALLBACK_SITES = (
     "join_keys",       # packed (doc, pos) join keys beyond int32
     "intersect_docs",  # doc ids beyond the intersect kernel's int32 keys
@@ -65,10 +71,11 @@ def interpret_mode() -> bool:
 class DeviceCounts:
     """What the device path did in one unit of work (a search batch).
 
-    ``launch`` records a Pallas kernel launch, split by whether it ran
-    compiled or interpreted; ``fallback`` records an exact host answer
-    taken at one of :data:`FALLBACK_SITES`.  Every count is made on the
-    thread that runs the batch's join and streaming stages.
+    ``launch`` records a launch of a program in :data:`SEARCH_KERNELS`,
+    split by whether it ran compiled or interpreted; ``fallback`` records
+    an exact host answer taken at one of :data:`FALLBACK_SITES`.  Every
+    count is made on the thread that runs the batch's join and streaming
+    stages.
     """
 
     def __init__(self) -> None:

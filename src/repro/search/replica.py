@@ -16,8 +16,8 @@ Topology (one fabric = the whole serving tier)::
 Each :class:`ReplicaReader` is one (shard, replica): per-index
 :class:`~repro.search.reader.IndexReader` snapshots over the shard's
 published storage with the replica's OWN posting cache and OWN search
-devices (``s{shard}r{replica}/{index}-read``), so read I/O is charged —
-and capacity measured — per replica.  Catch-up consumes the shard
+devices (``s{shard}r{replica}/{index}-read``), so read I/O is charged
+per replica.  Catch-up consumes the shard
 writer's touched-key digest stream (``digests_since``): a replica
 within the bounded digest history invalidates exactly the touched keys;
 one behind it falls back to the existing whole-namespace drop.  Both
@@ -41,7 +41,6 @@ catch up on revive, targeted or full-drop).
 
 from __future__ import annotations
 
-import time
 from typing import Callable, Dict, Hashable, List, Optional
 
 import numpy as np
@@ -80,8 +79,8 @@ class ReplicaReader:
         self.shard_set = shard_set
         self.cache = PostingCache(cache_bytes) if cache_bytes > 0 else None
         ns = f"s{self.shard_id}r{self.replica_id}"
-        # per-replica search devices: replica capacity and read traffic
-        # are measured per replica, never pooled into the writer's devices
+        # per-replica search devices: read traffic is charged per
+        # replica, never pooled into the writer's devices
         self.devices: Dict[str, BlockDevice] = {
             name: BlockDevice(
                 cluster_size=idx.cfg.cluster_size,
@@ -106,9 +105,6 @@ class ReplicaReader:
         self.waves_served = 0
         self.lookups_served = 0
         self.cursors_served = 0
-        # accumulated real serve seconds — the capacity denominator the
-        # --replicas bench scales by
-        self.busy_s = 0.0
         # injectable fault hook: called before every serve as
         # ``fault(replica, op)``; raise ReplicaDeadError to simulate a
         # crash mid-batch (the fabric then marks this replica dead and
@@ -129,11 +125,9 @@ class ReplicaReader:
 
     def lookup(self, index_name: str, key: Hashable) -> np.ndarray:
         self._check("lookup")
-        t0 = time.perf_counter()
         try:
             return self.readers[index_name].lookup(key)
         finally:
-            self.busy_s += time.perf_counter() - t0
             self.lookups_served += 1
 
     def open_cursor(
@@ -141,13 +135,11 @@ class ReplicaReader:
         make_decoder=None, device_tier: bool = False,
     ) -> ReaderCursor:
         self._check("cursor")
-        t0 = time.perf_counter()
         try:
             return self.readers[index_name].open_cursor(
                 key, make_decoder=make_decoder, device_tier=device_tier
             )
         finally:
-            self.busy_s += time.perf_counter() - t0
             self.cursors_served += 1
 
     # ---------------------------------------------------------- subscribing --
@@ -439,8 +431,6 @@ class ReplicaSetReader:
                         for row in self.replicas],
             "cursors": [[rep.cursors_served for rep in row]
                         for row in self.replicas],
-            "busy_s": [[rep.busy_s for rep in row]
-                       for row in self.replicas],
             "catch_ups": [[dict(rep.catch_ups) for rep in row]
                           for row in self.replicas],
         }

@@ -14,8 +14,9 @@ bools, so the "count" saturated at 1 and the partition still summed).
   against it — an undeclared key, wherever it was written, raises
   ``TraceIncompleteError``;
 * the static ``trace-schema`` lint pass (``repro.analysis``) validates
-  every ``last_trace[...]`` write in the *source tree* against it, so a
-  new key fails CI before any test drives the code path.
+  every ``last_trace[...]`` write in the *source tree* against it, and
+  every span name opened with ``<recorder>.span("...")``, so a new key
+  or span fails CI before any test drives the code path.
 
 Adding a trace field is a two-line change: declare it here, write it in
 the service.  Forgetting either half fails loudly on the other.
@@ -27,7 +28,8 @@ from typing import Dict, FrozenSet
 
 # Block name -> allowed keys.  "" is the top level of ``last_trace``;
 # the other blocks are the nested dicts stored under the same-named
-# top-level key ("topk", "cache", "replicas", "compactions", "device").
+# top-level key ("topk", "cache", "replicas", "compactions", "device",
+# "spans").
 TRACE_SCHEMA: Dict[str, FrozenSet[str]] = {
     "": frozenset({
         # scatter-fetch wave accounting (stage 2)
@@ -36,6 +38,7 @@ TRACE_SCHEMA: Dict[str, FrozenSet[str]] = {
         "prefetched_waves", "overlapped_finalizes", "shard_fetch_s",
         # batch-level pins and nested blocks
         "snapshot", "topk", "cache", "compactions", "replicas", "device",
+        "spans",
     }),
     "topk": frozenset({
         "queries", "ranked_queries",
@@ -52,7 +55,7 @@ TRACE_SCHEMA: Dict[str, FrozenSet[str]] = {
     }),
     "replicas": frozenset({
         "n_replicas", "snapshot", "live", "failovers", "failovers_batch",
-        "waves", "lookups", "cursors", "busy_s", "catch_ups",
+        "waves", "lookups", "cursors", "catch_ups",
     }),
     "compactions": frozenset({
         "compactions", "compacted_streams",
@@ -62,7 +65,23 @@ TRACE_SCHEMA: Dict[str, FrozenSet[str]] = {
     "device": frozenset({
         "compiled_launches", "interpreted_launches", "host_fallbacks",
     }),
+    # per-batch host spans (repro.core.spans.SpanRecorder): each name the
+    # program opens a span under, on the profiler's clock and in
+    # ``{name: {"s": seconds, "n": count}}``; ``index.decode`` also
+    # carries "bytes", the encoded posting bytes it decoded
+    "spans": frozenset({
+        "search.plan",        # refresh, snapshot pin, plan (serving thread)
+        "search.fetch_wait",  # serving thread waiting on the fetch waves
+        "search.fetch",       # one fetch wave, on the thread that runs it
+        "search.finalize",    # one query's non-join assembly and gather
+        "search.join",        # stages 3 and 4: bucketed joins and gather
+        "search.topk",        # one best-k query of the streaming stage
+        "index.decode",       # one host decode of posting bytes
+    }),
 }
+
+# the fields of one span's entry in ``last_trace["spans"]``
+SPAN_FIELDS: FrozenSet[str] = frozenset({"s", "n", "bytes"})
 
 # Counters that participate in a completeness partition (LHS == sum of
 # RHS members).  These MUST be incremented with integer expressions —
@@ -95,4 +114,8 @@ def validate_trace(trace: Dict[str, object]) -> str:
             for k in sub:
                 if k not in block:
                     return f"undeclared trace key {k!r} in block {key!r}"
+    for name, entry in (trace.get("spans") or {}).items():
+        for k in entry:
+            if k not in SPAN_FIELDS:
+                return f"undeclared field {k!r} of span {name!r}"
     return ""

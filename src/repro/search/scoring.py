@@ -156,12 +156,13 @@ def _jitted_score(n_slots: int, n_docs: int, cap: int):
     import jax
     import jax.numpy as jnp
 
-    def f(counts, weights):
+    # the function's name is the program's name in a profiler trace
+    def prox_score(counts, weights):
         return jnp.sum(
             weights[:, None] * jnp.minimum(counts, jnp.int32(cap)), axis=0
         )
 
-    return jax.jit(f)
+    return jax.jit(prox_score)
 
 
 def score_docs_jax(

@@ -34,6 +34,7 @@ to the unsharded numpy oracle.
 
 from __future__ import annotations
 
+import contextvars
 import dataclasses
 import time
 from concurrent.futures import ThreadPoolExecutor
@@ -67,6 +68,7 @@ from repro.search.plan import (
     plan_batch,
 )
 from repro.core.inverted_index import PostingCursor
+from repro.core.spans import SpanRecorder
 from repro.kernels import DeviceCounts
 from repro.kernels.posting_decode.ops import DeviceDecoder
 from repro.search.pool import ChunkPool
@@ -136,10 +138,14 @@ class SearchService:
     backends, off for numpy/callable.  Both knobs change I/O and
     residency only — results stay element-wise identical.
 
-    ``last_trace['device']`` reports, per batch, the Pallas launches
-    (compiled or interpreted) and every exact host fallback taken where a
-    value did not fit the device's int32 integers
-    (:class:`~repro.kernels.DeviceCounts`).
+    ``last_trace['device']`` reports, per batch, the device launches
+    (Pallas kernels compiled or interpreted, jax window-join buckets) and
+    every exact host fallback taken where a value did not fit the
+    device's int32 integers (:class:`~repro.kernels.DeviceCounts`).
+    ``last_trace['spans']`` reports the batch's host spans — plan, fetch
+    wait, fetch waves, posting decode, finalize, join, top-k — as seconds
+    and counts (:class:`~repro.core.spans.SpanRecorder`); each is also a
+    ``TraceAnnotation`` on the profiler's clock.
     """
 
     def __init__(
@@ -187,8 +193,10 @@ class SearchService:
                 f"{sorted(JOIN_BACKENDS)} or a callable"
             )
         self.share_chunks = bool(share_chunks)
-        # the current batch's device-path tally (fresh per search_batch)
+        # the current batch's device-path tally and host spans (fresh
+        # per search_batch)
         self._device = DeviceCounts()
+        self._spans = SpanRecorder()
         if device_decode is None:
             device_decode = self.backend in ("jax", "pallas")
         self.device_decode = bool(device_decode)
@@ -235,16 +243,26 @@ class SearchService:
         return self.search_batch([q])[0]
 
     def search_batch(self, queries: Sequence[QueryLike]) -> List[QueryResult]:
-        # pin the serving snapshot: apply any pending (targeted) cache
-        # invalidations NOW, then record the per-shard generation vector
-        # the whole batch executes against — a lookup mid-batch can never
-        # observe a different collection state than the plan did
-        self.reader.refresh()
-        snapshot = list(self.reader.generation_vector())
-        self._device = DeviceCounts()
-        cs = self.reader.cache_stats
-        rejects0 = cs.device_rejects if cs is not None else 0
-        plan = self.plan(queries)                               # stage 1
+        # the batch's span recorder is the one the index's decode spans
+        # find (repro.core.spans.current) while the batch runs
+        self._spans = SpanRecorder()
+        with self._spans.active():
+            return self._search_batch(queries)
+
+    def _search_batch(self, queries: Sequence[QueryLike]) -> List[QueryResult]:
+        spans = self._spans
+        with spans.span("search.plan"):
+            # pin the serving snapshot: apply any pending (targeted) cache
+            # invalidations NOW, then record the per-shard generation
+            # vector the whole batch executes against — a lookup mid-batch
+            # can never observe a different collection state than the
+            # plan did
+            self.reader.refresh()
+            snapshot = list(self.reader.generation_vector())
+            self._device = DeviceCounts()
+            cs = self.reader.cache_stats
+            rejects0 = cs.device_rejects if cs is not None else 0
+            plan = self.plan(queries)                           # stage 1
         results: List[Optional[QueryResult]] = [None] * len(plan.queries)
         ordinary: List[Tuple[int, List[ShardPosts]]] = []
         posts: Dict[Tuple[str, int], ShardPosts] = {}
@@ -283,13 +301,16 @@ class SearchService:
                 for qi in waiting.get(ident, ()):
                     pending[qi] -= 1
                     if pending[qi] == 0:
-                        self._finalize(plan, qi, posts, results, ordinary)
+                        with spans.span("search.finalize"):
+                            self._finalize(plan, qi, posts, results,
+                                           ordinary)
                         done += 1
             return done
 
         self._scatter_fetch(plan, posts, on_landed, batch_idents)  # stage 2
         self.last_trace["snapshot"] = snapshot
-        self._execute_ordinary(plan, ordinary, results)         # stages 3+4
+        with spans.span("search.join"):
+            self._execute_ordinary(plan, ordinary, results)     # stages 3+4
         self._execute_streaming(plan, streaming, results, posts)  # top-k stage
         now = list(self.reader.generation_vector())
         if now != snapshot:
@@ -306,6 +327,7 @@ class SearchService:
             # the device tier refuses inside the reader's cache admission
             self._device.fallback("device_rows", cs.device_rejects - rejects0)
         self.last_trace["device"] = self._device.as_trace()
+        self.last_trace["spans"] = spans.totals()
         self.check_trace_complete(plan)
         # serving-health counters: cumulative posting-cache stats (the
         # full_drops count is THE regression signal for targeted
@@ -341,7 +363,9 @@ class SearchService:
         (index, group) waves in order so lookups of the same dictionary
         group run back to back.  With ``prefetch`` on, wave ``i+1``'s
         device reads run on a worker thread while wave ``i``'s completed
-        queries finalize (host joins) on this thread.
+        queries finalize (host joins) on this thread.  Each wave is a
+        ``search.fetch`` span on the thread that runs it; the time this
+        thread spends waiting for a wave is ``search.fetch_wait``.
 
         Lookups needed ONLY by best-k queries are *deferred* to the
         streaming stage (recorded, never silently dropped): a wave whose
@@ -377,21 +401,23 @@ class SearchService:
         # in-flight-wave counter is the load signal routing balances on
         begin_wave = getattr(self.reader, "begin_wave", None)
         end_wave = getattr(self.reader, "end_wave", None)
+        spans = self._spans
 
         def fetch_wave(wave: List[KeyLookup]) -> List[Tuple[Tuple[str, int], ShardPosts]]:
             out = []
             if begin_wave is not None:
                 begin_wave()
             try:
-                for lk in wave:
-                    per_shard: ShardPosts = []
-                    for s in range(S):
-                        t0 = time.perf_counter()
-                        per_shard.append(
-                            self.reader.lookup_shard(s, lk.index, lk.key)
-                        )
-                        shard_s[s] += time.perf_counter() - t0
-                    out.append(((lk.index, lk.key), per_shard))
+                with spans.span("search.fetch"):
+                    for lk in wave:
+                        per_shard: ShardPosts = []
+                        for s in range(S):
+                            t0 = time.perf_counter()
+                            per_shard.append(
+                                self.reader.lookup_shard(s, lk.index, lk.key)
+                            )
+                            shard_s[s] += time.perf_counter() - t0
+                        out.append(((lk.index, lk.key), per_shard))
             finally:
                 if end_wave is not None:
                     end_wave()
@@ -406,18 +432,27 @@ class SearchService:
 
         if not self.prefetch or len(waves) <= 1:
             for wave in waves:
-                land(fetch_wave(wave), overlapping=False)
+                with spans.span("search.fetch_wait"):
+                    fetched = fetch_wave(wave)
+                land(fetched, overlapping=False)
         else:
             # exactly ONE worker: the readers and the shared posting cache
             # are only ever touched from the worker thread during the
-            # pipeline, while this thread runs the finalize joins
+            # pipeline, while this thread runs the finalize joins.  Each
+            # wave runs in a copy of this thread's context, so the index's
+            # decode spans find the batch's recorder there too
+            def submit(wave):
+                return pool.submit(contextvars.copy_context().run,
+                                   fetch_wave, wave)
+
             with ThreadPoolExecutor(max_workers=1) as pool:
-                fut = pool.submit(fetch_wave, waves[0])
+                fut = submit(waves[0])
                 for i in range(len(waves)):
-                    fetched = fut.result()
+                    with spans.span("search.fetch_wait"):
+                        fetched = fut.result()
                     overlapping = i + 1 < len(waves)
                     if overlapping:
-                        fut = pool.submit(fetch_wave, waves[i + 1])
+                        fut = submit(waves[i + 1])
                         trace["prefetched_waves"] += 1
                     land(fetched, overlapping)
         self.last_trace = trace
@@ -515,7 +550,7 @@ class SearchService:
         self, pairs: List[Tuple[np.ndarray, np.ndarray, int]]
     ) -> List[np.ndarray]:
         """Bucket join jobs by padded power-of-two shape; one vmapped
-        kernel launch per bucket."""
+        kernel launch per bucket, counted as a ``window_mask`` launch."""
         out: List[Optional[np.ndarray]] = [None] * len(pairs)
         buckets: Dict[Tuple[int, int, str], List] = {}
         for idx, (a, b, w) in enumerate(pairs):
@@ -550,6 +585,7 @@ class SearchService:
                 batched_window_mask(jnp.asarray(ak), jnp.asarray(bk),
                                     jnp.asarray(ws))
             )
+            self._device.launch("window_mask", interpret=False)
             for r, (idx, a, _akey, _bkey, _w) in enumerate(jobs):
                 out[idx] = a[mask[r, : a.shape[0]]]
         return out
@@ -596,10 +632,10 @@ class SearchService:
         # distinct identity), settled once after the batch
         settle: List[object] = []
         for qi in streaming:
-            t0 = time.perf_counter()
-            results[qi] = self._search_topk(plan.queries[qi], t,
-                                            posts or {}, pool, settle)
-            t["query_s"].append(time.perf_counter() - t0)
+            with self._spans.span("search.topk") as sp:
+                results[qi] = self._search_topk(plan.queries[qi], t,
+                                                posts or {}, pool, settle)
+            t["query_s"].append(sp.seconds)
         t["pool_streams"] = len(pool) if pool is not None else 0
         for rc in settle:
             settler = getattr(rc, "settle", None)

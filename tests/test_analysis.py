@@ -10,6 +10,8 @@ import subprocess
 import sys
 from pathlib import Path
 
+import pytest
+
 from repro.analysis import all_passes, lint_paths, lint_source
 from repro.analysis.passes import (
     CacheTierPass,
@@ -146,6 +148,34 @@ def test_trace_pragma_suppresses():
         "  # repro-lint: allow(trace-schema) migration shim\n"
     )
     assert findings(src, passes=[TraceSchemaPass()]) == []
+
+
+def test_trace_accepts_declared_spans_and_the_spans_block():
+    src = (
+        "class S:\n"
+        "    def f(self, rec):\n"
+        "        with rec.span('search.plan'):\n"
+        "            pass\n"
+        "        with self._spans.span('index.decode', 12):\n"
+        "            pass\n"
+        "        self.last_trace['spans'] = rec.totals()\n"
+    )
+    assert findings(src, passes=[TraceSchemaPass()]) == []
+
+
+@pytest.mark.parametrize("call,needle", [
+    ("rec.span('search.plann')", "search.plann"),
+    ("self._spans.span(name)", "literal"),
+])
+def test_trace_flags_undeclared_or_computed_span_name(call, needle):
+    src = (
+        "class S:\n"
+        "    def f(self, rec, name):\n"
+        f"        with {call}:\n"
+        "            pass\n"
+    )
+    fs = findings(src, passes=[TraceSchemaPass()])
+    assert len(fs) == 1 and needle in fs[0].message
 
 
 def test_runtime_and_static_registries_cannot_drift():
