@@ -7,7 +7,9 @@ local later stored into it, or through a dict parameter named ``trace``)
 must appear in ``repro.search.schema.TRACE_SCHEMA``.  So must every span
 name: the first argument of a ``<recorder>.span(...)`` call is a string
 literal declared in ``TRACE_SCHEMA["spans"]``, the block
-``last_trace["spans"]`` reports them in.  Counters that are
+``last_trace["spans"]`` reports them in; a ``<recorder>.tally(name,
+field=n)`` call names a declared span too, and each field is one of
+``SPAN_FIELDS``.  Counters that are
 members of a completeness partition must additionally be written with
 integer expressions — PR 7 accumulated ``any(...)`` bools into
 ``early_terminated``, which saturated the count at 1 per batch while
@@ -21,7 +23,7 @@ from typing import Dict, Iterator, List, Optional, Tuple
 
 from repro.analysis.engine import LintPass
 from repro.analysis.schema import Finding
-from repro.search.schema import TRACE_COUNTERS, TRACE_SCHEMA
+from repro.search.schema import SPAN_FIELDS, TRACE_COUNTERS, TRACE_SCHEMA
 
 ALL_TRACE_KEYS = frozenset().union(*TRACE_SCHEMA.values())
 SPAN_NAMES = TRACE_SCHEMA["spans"]
@@ -105,7 +107,7 @@ class TraceSchemaPass(LintPass):
         for node in ast.walk(tree):
             if not (isinstance(node, ast.Call)
                     and isinstance(node.func, ast.Attribute)
-                    and node.func.attr == "span" and node.args):
+                    and node.func.attr in ("span", "tally") and node.args):
                 continue
             arg = node.args[0]
             if not (isinstance(arg, ast.Constant)
@@ -121,6 +123,14 @@ class TraceSchemaPass(LintPass):
                     f"span {arg.value!r} is not declared in "
                     f"repro.search.schema.TRACE_SCHEMA['spans']",
                 ))
+            if node.func.attr == "tally":
+                for kw in node.keywords:
+                    if kw.arg not in SPAN_FIELDS:
+                        out.append(self.finding(
+                            path, node,
+                            f"span field {kw.arg!r} is not declared in "
+                            f"repro.search.schema.SPAN_FIELDS",
+                        ))
         return out
 
     # -------------------------------------------------------------------

@@ -6,10 +6,13 @@ batch).  Entering ``recorder.span(name)`` does two things: it opens a
 profiler's host plane on the same clock as the device events, and on
 exit it adds the span's ``perf_counter`` seconds and a count of 1 (and,
 where given, a byte count) to the recorder's totals for the thread that
-ran it.  :meth:`SpanRecorder.totals` merges the threads' totals into the
-``{name: {"s": seconds, "n": count[, "bytes": n]}}`` block that
+ran it.  :meth:`SpanRecorder.tally` adds further counts to a span's
+entry (the byte-parallel decodes, :func:`note_parallel_decode`).
+:meth:`SpanRecorder.totals` merges the threads' totals into the
+``{name: {"s": seconds, "n": count[, "bytes": n, ...]}}`` block that
 ``SearchService.last_trace["spans"]`` carries; the names are declared in
-``repro.search.schema.TRACE_SCHEMA["spans"]``.
+``repro.search.schema.TRACE_SCHEMA["spans"]``, the fields in
+``repro.search.schema.SPAN_FIELDS``.
 
 Code below the service (the index's posting decode) finds the batch's
 recorder through :func:`current`: a context variable that
@@ -72,8 +75,8 @@ class SpanRecorder:
         from jax.profiler import TraceAnnotation
 
         self._annotation = TraceAnnotation
-        # thread id -> name -> [seconds, count, bytes or None]; each
-        # thread writes only its own dict
+        # thread id -> name -> [seconds, count, {field: count} or None];
+        # each thread writes only its own dict
         self._per_thread: Dict[int, Dict[str, List]] = {}
 
     def span(self, name: str, nbytes: Optional[int] = None) -> _Span:
@@ -81,7 +84,7 @@ class SpanRecorder:
         duration once it has exited."""
         return _Span(self, name, nbytes)
 
-    def _add(self, name: str, seconds: float, nbytes: Optional[int]) -> None:
+    def _row(self, name: str) -> List:
         tid = threading.get_ident()
         tot = self._per_thread.get(tid)
         if tot is None:
@@ -89,10 +92,28 @@ class SpanRecorder:
         row = tot.get(name)
         if row is None:
             row = tot[name] = [0.0, 0, None]
+        return row
+
+    def _add(self, name: str, seconds: float, nbytes: Optional[int]) -> None:
+        row = self._row(name)
         row[0] += seconds
         row[1] += 1
         if nbytes is not None:
-            row[2] = (row[2] or 0) + int(nbytes)
+            self._bump(row, "bytes", nbytes)
+
+    def tally(self, name: str, **fields: int) -> None:
+        """Add counts to fields of span ``name``'s entry besides its
+        seconds and count (each field declared in
+        ``repro.search.schema.SPAN_FIELDS``)."""
+        row = self._row(name)
+        for field, value in fields.items():
+            self._bump(row, field, value)
+
+    @staticmethod
+    def _bump(row: List, field: str, value: int) -> None:
+        if row[2] is None:
+            row[2] = {}
+        row[2][field] = row[2].get(field, 0) + int(value)
 
     @contextlib.contextmanager
     def active(self) -> Iterator["SpanRecorder"]:
@@ -107,12 +128,12 @@ class SpanRecorder:
         """Every thread's totals, merged by name."""
         out: Dict[str, Dict[str, float]] = {}
         for tot in list(self._per_thread.values()):
-            for name, (s, n, nbytes) in tot.items():
+            for name, (s, n, fields) in tot.items():
                 row = out.setdefault(name, {"s": 0.0, "n": 0})
                 row["s"] += s
                 row["n"] += n
-                if nbytes is not None:
-                    row["bytes"] = row.get("bytes", 0) + nbytes
+                for field, value in (fields or {}).items():
+                    row[field] = row.get(field, 0) + value
         return out
 
 
@@ -123,3 +144,12 @@ def decode_span(nbytes: int):
     if rec is None:
         return contextlib.nullcontext()
     return rec.span("index.decode", nbytes)
+
+
+def note_parallel_decode(nbytes: int) -> None:
+    """Count one byte-parallel posting decode of ``nbytes`` encoded bytes
+    into the current batch's ``index.decode`` entry (``parallel``,
+    ``parallel_bytes``); a no-op outside a batch."""
+    rec = _CURRENT.get()
+    if rec is not None:
+        rec.tally("index.decode", parallel=1, parallel_bytes=nbytes)

@@ -68,7 +68,8 @@ TRACE_SCHEMA: Dict[str, FrozenSet[str]] = {
     # per-batch host spans (repro.core.spans.SpanRecorder): each name the
     # program opens a span under, on the profiler's clock and in
     # ``{name: {"s": seconds, "n": count}}``; ``index.decode`` also
-    # carries "bytes", the encoded posting bytes it decoded
+    # carries "bytes", the encoded posting bytes it decoded, and, where
+    # any took the byte-parallel path, "parallel" and "parallel_bytes"
     "spans": frozenset({
         "search.plan",        # refresh, snapshot pin, plan (serving thread)
         "search.fetch_wait",  # serving thread waiting on the fetch waves
@@ -80,8 +81,12 @@ TRACE_SCHEMA: Dict[str, FrozenSet[str]] = {
     }),
 }
 
-# the fields of one span's entry in ``last_trace["spans"]``
-SPAN_FIELDS: FrozenSet[str] = frozenset({"s", "n", "bytes"})
+# the fields of one span's entry in ``last_trace["spans"]``: seconds,
+# count, encoded bytes, and the decodes (and their bytes) that took the
+# byte-parallel path (``repro.core.postings.PARALLEL_MIN_BYTES``)
+SPAN_FIELDS: FrozenSet[str] = frozenset({
+    "s", "n", "bytes", "parallel", "parallel_bytes",
+})
 
 # Counters that participate in a completeness partition (LHS == sum of
 # RHS members).  These MUST be incremented with integer expressions —

@@ -158,6 +158,7 @@ def test_trace_accepts_declared_spans_and_the_spans_block():
         "            pass\n"
         "        with self._spans.span('index.decode', 12):\n"
         "            pass\n"
+        "        rec.tally('index.decode', parallel=1, parallel_bytes=12)\n"
         "        self.last_trace['spans'] = rec.totals()\n"
     )
     assert findings(src, passes=[TraceSchemaPass()]) == []
@@ -166,6 +167,8 @@ def test_trace_accepts_declared_spans_and_the_spans_block():
 @pytest.mark.parametrize("call,needle", [
     ("rec.span('search.plann')", "search.plann"),
     ("self._spans.span(name)", "literal"),
+    ("rec.tally('index.decod', parallel=1)", "index.decod"),
+    ("rec.tally('index.decode', parallel_ms=1)", "parallel_ms"),
 ])
 def test_trace_flags_undeclared_or_computed_span_name(call, needle):
     src = (

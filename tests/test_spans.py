@@ -4,7 +4,7 @@
 program's spans (``repro.core.spans``), merged over the serving thread
 and the fetch worker; each span is also a ``TraceAnnotation`` on the
 profiler's clock.  ``index.decode`` counts the encoded posting bytes it
-decoded.  ``last_trace["device"]`` counts the jax join's launches
+decoded, and the decodes (and bytes) that took the byte-parallel path.  ``last_trace["device"]`` counts the jax join's launches
 (``window_mask``).  The span names are declared in the trace schema, and
 the device programs keep stable names in a profiler trace.
 """
@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from repro.core import spans as spans_mod
 from repro.core.dictionary import K_EM
 from repro.core.lexicon import make_lexicon
+from repro.core.postings import PARALLEL_MIN_BYTES
 from repro.core.strategies import StrategyConfig
 from repro.core.text_index import IndexSetConfig, TextIndexSet
 from repro.data.corpus import generate_part
@@ -123,6 +124,44 @@ def test_decode_bytes_are_the_lists_that_missed_the_cache(world, cache_bytes):
         assert dec is None
     else:
         assert dec["bytes"] == want
+
+
+def test_parallel_decodes_are_the_lists_past_the_threshold(world):
+    ts, qs = world
+    svc = SearchService(ts, backend="numpy", cache_bytes=0)
+    plan = svc.plan(qs)
+    sizes = [_encoded_bytes(ts, i) for i in
+             {(lk.index, lk.key) for pq in plan.queries for lk in pq.lookups}]
+    big = [n for n in sizes if n >= PARALLEL_MIN_BYTES]
+    # the batch decodes lists on both sides: small EM lists in the loop
+    assert big and any(0 < n < PARALLEL_MIN_BYTES for n in sizes)
+    svc.search_batch(qs)
+    dec = svc.last_trace["spans"]["index.decode"]
+    assert dec["n"] == sum(n > 0 for n in sizes)
+    assert dec["parallel"] == len(big)
+    assert dec["parallel_bytes"] == sum(big)
+    assert dec["bytes"] == sum(sizes)
+    assert validate_trace(svc.last_trace) == ""
+
+
+def test_span_recorder_tallies_fields_across_threads():
+    rec = spans_mod.SpanRecorder()
+
+    def work():
+        with rec.active():
+            spans_mod.note_parallel_decode(300)
+
+    t = threading.Thread(target=work)
+    t.start()
+    t.join()
+    with rec.span("index.decode", 500):
+        rec.tally("index.decode", parallel=1, parallel_bytes=500)
+    dec = rec.totals()["index.decode"]
+    assert dec["n"] == 1 and dec["bytes"] == 500
+    assert dec["parallel"] == 2 and dec["parallel_bytes"] == 800
+    # outside a batch nothing is counted
+    spans_mod.note_parallel_decode(999)
+    assert rec.totals()["index.decode"]["parallel"] == 2
 
 
 def test_fetch_waves_on_the_worker_merge_into_the_batch(world, monkeypatch):
@@ -251,6 +290,14 @@ def test_trace_check_refuses_an_undeclared_span():
         {"spans": {"search.bogus": {"s": 0.1, "n": 1}}})
     assert "ms" in validate_trace(
         {"spans": {"search.plan": {"ms": 0.1, "n": 1}}})
+
+
+def test_trace_check_accepts_the_parallel_decode_fields_only():
+    entry = {"s": 0.1, "n": 3, "bytes": 900, "parallel": 1,
+             "parallel_bytes": 800}
+    assert validate_trace({"spans": {"index.decode": entry}}) == ""
+    bad = dict(entry, parallel_ms=0.2)
+    assert "parallel_ms" in validate_trace({"spans": {"index.decode": bad}})
 
 
 # --------------------------------------------------- device launches --
